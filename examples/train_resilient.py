@@ -25,6 +25,6 @@ if __name__ == "__main__":
             "--scratch", "/tmp/veloc_resilient"]
     if not full:
         args += ["--smoke"] if os.environ.get("VELOC_SMOKE") else []
-    losses = main(args)
+    losses = main(args).losses
     assert losses[-1] < losses[0], "loss should decrease"
     print("resilient training example OK")

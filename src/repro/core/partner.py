@@ -30,8 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import runtime
-from repro.kernels.xor_parity import xor_pair_pallas
+from repro.kernels import xor_parity as _xp
+from repro.kernels.ops import interpret_mode
 
 
 def flatten_local_u32(tree):
@@ -80,7 +80,7 @@ def encode_l2(state, pspecs, mesh, *, mode: str = "xor", axis: str = "data",
     the L2 artifact its host must persist (partner copy or parity stripe)."""
     G = mesh.shape[axis]
     assert G >= 2, "L2 encode needs >=2 slots on the partner axis"
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     all_axes = tuple(mesh.axis_names)
 
     def inner(tree):
@@ -97,14 +97,15 @@ def encode_l2(state, pspecs, mesh, *, mode: str = "xor", axis: str = "data",
             recv = jax.lax.ppermute(acc, axis, perm)
             nxt = jax.lax.dynamic_index_in_dim(xs, (g - 2 - i) % G,
                                                keepdims=False)
-            return xor_pair_pallas(_pad_to(recv, 1024), _pad_to(nxt, 1024),
-                                   interpret=interpret)[:c]
+            tile = _xp.tile_words(c, _xp.BLOCK_N)
+            return _xp.xor_pair_pallas(_pad_to(recv, tile), _pad_to(nxt, tile),
+                                       interpret=interpret)[:c]
 
         init = jax.lax.dynamic_index_in_dim(xs, (g - 1) % G, keepdims=False)
         return jax.lax.fori_loop(0, G - 1, step, init)
 
-    fn = runtime.shard_map(inner, mesh=mesh, in_specs=(pspecs,),
-                           out_specs=P(all_axes), check_vma=False)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(pspecs,),
+                       out_specs=P(all_axes), check_vma=False)
     return fn(state)
 
 

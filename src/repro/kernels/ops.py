@@ -1,8 +1,9 @@
 """Jitted public wrappers over the Pallas kernels.
 
 Handles arbitrary byte buffers: pad + reshape into kernel tiling, dispatch
-(interpret mode on CPU, compiled on TPU), unpad.  These are the primitives
-the VELOC modules (checksum / compress / erasure-encode) call.
+(interpret mode on CPU, compiled on TPU, refused anywhere else), unpad.
+These are the primitives the VELOC modules (checksum / compress /
+erasure-encode) call.
 """
 from __future__ import annotations
 
@@ -17,21 +18,36 @@ from repro.kernels import quantize as _qz
 from repro.kernels import xor_parity as _xp
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted: on the CPU backend
+    (tests, host-only runs) they do; on a TPU they are compiled.  Any other
+    backend has no Mosaic lowering and no business interpreting device
+    work, so it is an error rather than a silent slow path."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels support the 'tpu' and 'cpu' backends, not "
+        f"{platform!r}")
 
 
 #: Lifetime kernel-dispatch counters (benchmarks and tests read deltas to
 #: assert batching actually collapses per-chunk dispatches into one).
-KERNEL_DISPATCHES = {"checksum": 0, "blockhash": 0, "gather": 0}
+KERNEL_DISPATCHES = {"checksum": 0, "blockhash": 0, "gather": 0, "xor": 0}
 
 
-def _pad_to(x: np.ndarray | jax.Array, mult: int):
-    n = x.shape[-1]
-    pad = (-n) % mult
-    if pad:
-        x = jnp.concatenate([jnp.asarray(x), jnp.zeros((pad,), x.dtype)])
-    return jnp.asarray(x), n
+def _pad_last(x, total: int):
+    """Zero-pad the last axis of ``x`` to ``total``: on the host for a
+    NumPy array (so a new length costs no device compile), on the device
+    for a device array."""
+    pad = total - x.shape[-1]
+    if not pad:
+        return x
+    widths = [(0, 0)] * (x.ndim - 1) + [(0, pad)]
+    return np.pad(x, widths) if isinstance(x, np.ndarray) \
+        else jnp.pad(x, widths)
 
 
 def bytes_to_u32(buf: bytes | np.ndarray) -> np.ndarray:
@@ -55,25 +71,13 @@ def _xor_reduce_j(x, interpret=True):
     return _xp.xor_reduce_pallas(x, interpret=interpret)
 
 
-def xor_reduce(x) -> jax.Array:
-    """x: (K, N) uint32 -> (N,) parity (pads N to the tile size)."""
-    x = jnp.asarray(x)
+def xor_reduce(x) -> np.ndarray:
+    """x: (K, N) uint32 -> (N,) host parity (pads N to the tile size)."""
     K, n = x.shape
-    pad = (-n) % _xp.BLOCK_N
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((K, pad), x.dtype)], axis=1)
-    return _xor_reduce_j(x, interpret=_interpret())[:n]
-
-
-@partial(jax.jit, static_argnames=("interpret",))
-def _xor_pair_j(a, b, interpret=True):
-    return _xp.xor_pair_pallas(a, b, interpret=interpret)
-
-
-def xor_pair(a, b) -> jax.Array:
-    a, n = _pad_to(jnp.asarray(a), _xp.BLOCK_N)
-    b, _ = _pad_to(jnp.asarray(b), _xp.BLOCK_N)
-    return _xor_pair_j(a, b, interpret=_interpret())[:n]
+    KERNEL_DISPATCHES["xor"] += 1
+    tile = _xp.tile_words(n, _xp.block_words(K))
+    x = jnp.asarray(_pad_last(x, -(-n // tile) * tile))
+    return np.asarray(_xor_reduce_j(x, interpret=interpret_mode()))[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -86,20 +90,33 @@ def _checksum_j(x, interpret=True):
     return _ck.checksum_pallas(x, interpret=interpret)
 
 
+def padded_rows(rows: int) -> int:
+    """Row count the row kernels run at for ``rows`` chunk rows: whole
+    8-row sublane tiles up to one ``BLOCK_ROWS`` tile, whole tiles beyond —
+    so ``checksum.row_block`` always finds a tile that divides it.  Zero
+    rows fold as the identity (``fold_digest``) and are sliced off the
+    fingerprint tables, so padding never changes a value."""
+    unit = 8 if rows <= _ck.BLOCK_ROWS else _ck.BLOCK_ROWS
+    return -(-rows // unit) * unit
+
+
+def _row_tiling(words, chunk: int):
+    """(rows, chunk) zero-padded tiling of a flat word vector plus its
+    unpadded row count.  Host words are padded on the host, so each new
+    buffer length costs no device compile."""
+    rows = -(-words.shape[0] // chunk)
+    words = _pad_last(words, padded_rows(rows) * chunk)
+    return jnp.asarray(words).reshape(-1, chunk), rows
+
+
 def fletcher_chunks(words: jax.Array | np.ndarray,
                     chunk: int = _ck.CHUNK_WORDS) -> np.ndarray:
     """words: (n,) uint32 -> (n_chunks, 2) uint32 per-chunk checksums."""
-    w = jnp.asarray(words)
-    if w.shape[0] == 0:
+    if words.shape[0] == 0:
         return np.zeros((0, 2), np.uint32)
     KERNEL_DISPATCHES["checksum"] += 1
-    rows = -(-w.shape[0] // chunk)
-    rows_pad = -(-rows // _ck.BLOCK_ROWS) * _ck.BLOCK_ROWS
-    total = rows_pad * chunk
-    if total != w.shape[0]:
-        w = jnp.concatenate([w, jnp.zeros((total - w.shape[0],), jnp.uint32)])
-    out = _checksum_j(w.reshape(rows_pad, chunk), interpret=_interpret())
-    return np.asarray(out[:rows])
+    tiles, rows = _row_tiling(words, chunk)
+    return np.asarray(_checksum_j(tiles, interpret=interpret_mode()))[:rows]
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -118,19 +135,9 @@ def block_fingerprints(buf: bytes | np.ndarray,
     words = bytes_to_u32(buf)
     if words.shape[0] == 0:
         return np.zeros((0, 2), np.uint32)
-    chunk = chunk_bytes // 4
-    rows = -(-words.shape[0] // chunk)
-    # single-tile inputs run at their natural row count (blockhash_pallas
-    # shrinks block_rows to n); only multi-tile inputs pad to the tile grid.
-    rows_pad = rows if rows <= _ck.BLOCK_ROWS \
-        else -(-rows // _ck.BLOCK_ROWS) * _ck.BLOCK_ROWS
-    total = rows_pad * chunk
-    w = jnp.asarray(words)
-    if total != w.shape[0]:
-        w = jnp.concatenate([w, jnp.zeros((total - w.shape[0],), jnp.uint32)])
+    tiles, rows = _row_tiling(words, chunk_bytes // 4)
     KERNEL_DISPATCHES["blockhash"] += 1
-    out = _blockhash_j(w.reshape(rows_pad, chunk), interpret=_interpret())
-    return np.asarray(out[:rows])
+    return np.asarray(_blockhash_j(tiles, interpret=interpret_mode()))[:rows]
 
 
 def fold_digest(chunks: np.ndarray, n_words: int) -> str:
@@ -192,17 +199,20 @@ def chunk_digests(blobs) -> list[str]:
 
 @partial(jax.jit, static_argnames=("total",))
 def _device_words_j(flat, total):
-    if flat.dtype.itemsize == 4:
-        w = jax.lax.bitcast_convert_type(flat, jnp.uint32)
-    else:
-        # little-endian byte stream of the flat array, then shift-combined
-        # into words — bit-identical to host bytes_to_u32 of the same bytes.
-        b = jax.lax.bitcast_convert_type(flat, jnp.uint8).reshape(-1)
-        pad = (-b.shape[0]) % 4
-        if pad:
-            b = jnp.concatenate([b, jnp.zeros((pad,), jnp.uint8)])
-        q = b.reshape(-1, 4).astype(jnp.uint32)
-        w = q[:, 0] | (q[:, 1] << 8) | (q[:, 2] << 16) | (q[:, 3] << 24)
+    # little-endian: element j of each group of 4 // itemsize fills bits
+    # [8 * itemsize * j, ...) of its word — bit-identical to host
+    # bytes_to_u32 of the same bytes.  Strided 1-D slices, not an
+    # (n, 4 // itemsize) view: on TPU that view pads its minor dim to 128
+    # lanes, and a 64 MiB bf16 leaf then took ~90 s to compile for v5e.
+    size = flat.dtype.itemsize
+    ratio = 4 // size
+    u = jax.lax.bitcast_convert_type(flat, jnp.dtype(f"uint{8 * size}"))
+    pad = (-u.shape[0]) % ratio
+    if pad:
+        u = jnp.concatenate([u, jnp.zeros((pad,), u.dtype)])
+    w = u[0::ratio].astype(jnp.uint32)
+    for j in range(1, ratio):
+        w = w | (u[j::ratio].astype(jnp.uint32) << (8 * size * j))
     if w.shape[0] < total:
         w = jnp.concatenate([w, jnp.zeros((total - w.shape[0],), jnp.uint32)])
     return w
@@ -220,8 +230,7 @@ def device_words(x, chunk_bytes: int):
     nbytes = int(flat.size) * flat.dtype.itemsize
     n_words = -(-nbytes // 4)
     rows = -(-n_words // chunk)
-    rows_pad = rows if rows <= _ck.BLOCK_ROWS \
-        else -(-rows // _ck.BLOCK_ROWS) * _ck.BLOCK_ROWS
+    rows_pad = padded_rows(rows)
     w = _device_words_j(flat, rows_pad * chunk)
     return w.reshape(rows_pad, chunk), n_words, rows
 
@@ -230,7 +239,7 @@ def device_fingerprints(words2d) -> jax.Array:
     """Block fingerprints of a device word tiling; the result STAYS on
     device (same kernel/values as ``block_fingerprints``, no D2H)."""
     KERNEL_DISPATCHES["blockhash"] += 1
-    return _blockhash_j(words2d, interpret=_interpret())
+    return _blockhash_j(words2d, interpret=interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -244,7 +253,7 @@ def fingerprint_diff(words2d, prev_fp):
     fingerprint input ever leaves HBM.  Only the chunk-sized dirty mask
     (and whatever chunks it selects) needs to cross PCIe."""
     KERNEL_DISPATCHES["blockhash"] += 1
-    return _blockhash_diff_j(words2d, prev_fp, interpret=_interpret())
+    return _blockhash_diff_j(words2d, prev_fp, interpret=interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -258,7 +267,7 @@ def gather_rows(words2d, idx):
     ``len(idx)`` chunks instead of the whole region."""
     KERNEL_DISPATCHES["gather"] += 1
     return _gather_j(words2d, jnp.asarray(idx, jnp.int32),
-                     interpret=_interpret())
+                     interpret=interpret_mode())
 
 
 # ---------------------------------------------------------------------------
@@ -277,24 +286,24 @@ def _dequant_j(q, s, interpret=True):
 
 
 def quantize(x: np.ndarray | jax.Array):
-    """x: any-shape float array -> (q int8 flat, scales f32, orig_len, shape)."""
-    shape = tuple(np.asarray(x.shape))
-    flat = jnp.asarray(x).reshape(-1).astype(jnp.float32)
+    """x: any-shape float array -> (q int8 (rows, BLOCK_SIZE), scales f32,
+    orig_len, shape).  Rows pad to whole ``BLOCK_ROWS`` kernel tiles."""
+    shape = tuple(x.shape)
+    flat = x.reshape(-1).astype(np.float32)
     n = flat.shape[0]
-    bs = _qz.BLOCK_SIZE
-    rows = -(-n // bs)
+    rows = -(-n // _qz.BLOCK_SIZE)
     rows_pad = -(-rows // _qz.BLOCK_ROWS) * _qz.BLOCK_ROWS
-    if rows_pad * bs != n:
-        flat = jnp.concatenate([flat, jnp.zeros((rows_pad * bs - n,), jnp.float32)])
-    q, s = _quant_j(flat.reshape(rows_pad, bs), interpret=_interpret())
-    return np.asarray(q[:rows]), np.asarray(s[:rows]), n, shape
+    tiles = jnp.asarray(_pad_last(flat, rows_pad * _qz.BLOCK_SIZE))
+    q, s = _quant_j(tiles.reshape(rows_pad, _qz.BLOCK_SIZE),
+                    interpret=interpret_mode())
+    return np.asarray(q)[:rows], np.asarray(s)[:rows], n, shape
 
 
 def dequantize(q: np.ndarray, scales: np.ndarray, n: int, shape) -> np.ndarray:
     rows = q.shape[0]
-    rows_pad = -(-rows // _qz.BLOCK_ROWS) * _qz.BLOCK_ROWS
-    if rows_pad != rows:
-        q = np.concatenate([q, np.zeros((rows_pad - rows, q.shape[1]), np.int8)])
-        scales = np.concatenate([scales, np.zeros((rows_pad - rows,), np.float32)])
-    out = _dequant_j(jnp.asarray(q), jnp.asarray(scales), interpret=_interpret())
+    pad = -(-rows // _qz.BLOCK_ROWS) * _qz.BLOCK_ROWS - rows
+    q = np.pad(q, ((0, pad), (0, 0)))
+    scales = np.pad(scales, (0, pad))
+    out = _dequant_j(jnp.asarray(q), jnp.asarray(scales),
+                     interpret=interpret_mode())
     return np.asarray(out).reshape(-1)[:n].reshape(shape)

@@ -2,8 +2,10 @@
 compression module for lossy checkpoint compression, 2-4x size reduction).
 
 Each row of ``block_size`` values gets an absmax scale: q = round(x/s),
-s = absmax/127.  Streaming, bandwidth-bound; tiles of ``block_rows`` rows
-keep the working set in VMEM and the lane dim 128-aligned.
+s = absmax/127.  Streaming, bandwidth-bound; tiles of ``BLOCK_ROWS`` rows
+keep the working set in VMEM.  The scales are a 1-D f32 vector, which the
+TPU lays out in 1024-element tiles, so a tile spans 1024 rows and its
+scale block is one whole layout tile.
 """
 from __future__ import annotations
 
@@ -12,7 +14,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BLOCK_SIZE = 256  # values per quantization block (one scale each)
-BLOCK_ROWS = 256  # 256 x 256 x 4B = 256 KiB per tile
+BLOCK_ROWS = 1024  # 1024 x 256 x 4B = 1 MiB per f32 tile
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):

@@ -5,21 +5,13 @@ touches jax device state (jax locks the device count on first init)."""
 from __future__ import annotations
 
 import jax
-
-
-def _mesh_kwargs(naxes: int) -> dict:
-    """axis_types only exists on newer jax (>=0.5); older versions default
-    to Auto axes, so omitting the kwarg is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * naxes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -27,4 +19,5 @@ def make_host_mesh(data: int = 1, model: int = 1):
     smoke/multidevice tests and the CPU demo driver."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"), **_mesh_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -9,14 +9,22 @@ Features exercised for real (CPU host):
   - async multi-level pipeline (local + partner/XOR + external flush);
   - phase-predictor-gated, rate-limited background flushing;
   - automatic restart from the newest restorable level (--resume);
-  - simulated node failure (--fail-at N) followed by recovery;
+  - simulated node failure (--fail-at N) followed by recovery and replay
+    from the restored step;
   - DataStates lineage recording per checkpoint.
+
+A checkpoint that fails, a background pipeline error, or a --resume that
+finds nothing restorable (without --cold-start-ok) raises, so the process
+exits non-zero instead of training on unprotected.
 """
 import argparse
 import time
+from dataclasses import dataclass
+from typing import Any, Optional
 
 import jax
 
+from repro import runtime
 from repro.configs.base import ShapeCfg, get_config, smoke_config
 from repro.core import (Cluster, DataStates, ModuleSpec, PipelineSpec,
                         TierTopology, VelocClient)
@@ -30,7 +38,48 @@ def build(arch: str, smoke: bool, seq_len: int, batch: int):
     return cfg, shape
 
 
-def main(argv=None):
+#: seconds to wait for in-flight checkpoints to drain (failure simulation
+#: and end of run) before the run is declared failed
+SETTLE_TIMEOUT_S = 600.0
+
+
+@dataclass
+class TrainRun:
+    """What a training run leaves behind: per-step losses, the final train
+    state (device arrays), the newest checkpoint version that persisted
+    (None when checkpointing was off or nothing was saved), the version the
+    failure simulation restored (None when it did not fire), and the
+    checkpoint pipeline (what a restarted process rebuilds its client
+    from)."""
+
+    losses: list
+    state: Any
+    version: Optional[int]
+    restored_from: Optional[int]
+    pipeline: PipelineSpec
+
+
+def _settle(futures, timeout: float) -> Optional[int]:
+    """Wait for every checkpoint future; raise the first failure (a pipeline
+    exception, or a level that reported an error).  A version superseded by
+    a newer one before it ran is not a failure.  Returns the newest version
+    that persisted."""
+    newest = None
+    for fut in futures:
+        if fut.skipped:
+            continue
+        if fut.exception(timeout) is not None and fut.superseded:
+            continue
+        fut.result(timeout)
+        if fut.module_errors:
+            raise RuntimeError(
+                f"checkpoint v{fut.version}: levels failed "
+                f"{fut.module_errors}: {fut.results.get('errors')}")
+        newest = fut.version if newest is None else max(newest, fut.version)
+    return newest
+
+
+def main(argv=None) -> TrainRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="veloc-demo-100m")
     ap.add_argument("--smoke", action="store_true",
@@ -69,11 +118,15 @@ def main(argv=None):
                     help="admission high-water mark: over this many "
                          "queued+running checkpoints, new ones skip")
     ap.add_argument("--resume", action="store_true")
+    ap.add_argument("--cold-start-ok", action="store_true",
+                    help="with --resume, start from step 0 when nothing is "
+                         "restorable instead of failing")
     ap.add_argument("--fail-at", type=int, default=-1,
                     help="simulate node failure after this step")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    runtime.use_compile_cache()
     cfg, shape = build(args.arch, args.smoke, args.seq_len, args.batch)
     key = jax.random.PRNGKey(args.seed)
     stream = SyntheticStream(cfg, shape, seed=1234)
@@ -103,28 +156,41 @@ def main(argv=None):
     if args.mode != "off":
         client = VelocClient(pipeline,
                              Cluster(TierTopology(scratch=args.scratch)))
-    ds = DataStates(client.cluster) if client else None
+    try:
+        return _train(args, cfg, key, stream, client, pipeline)
+    finally:
+        if client:
+            client.shutdown()
 
+
+def _train(args, cfg, key, stream, client, pipeline) -> TrainRun:
+    ds = DataStates(client.cluster) if client else None
     state = init_train_state(key, cfg)
-    start_step = 0
+    step = 0
     if args.resume and client is not None:
         v, restored = client.restart_latest(state)
         if v is not None:
-            state, start_step = restored, v
+            state, step = restored, v
             print(f"[veloc] resumed from checkpoint v{v}")
         else:
-            print("[veloc] no checkpoint found; cold start")
             for d in client.restart_diagnostics:
                 print(f"[veloc]   v{d['version']} ({d['level']}) skipped: "
                       f"{d['error']}")
+            if not args.cold_start_ok:
+                raise RuntimeError(
+                    "--resume found no restorable checkpoint under "
+                    f"{args.scratch} (pass --cold-start-ok to start fresh)")
+            print("[veloc] no checkpoint found; cold start")
 
     capture = args.capture == "fused" and args.mode != "off"
     step_fn = jax.jit(make_train_step(cfg, lr=args.lr, capture=capture),
                       donate_argnums=(0,))
 
     losses = []
+    futures = []
+    restored_from = None
     t_start = time.time()
-    for step in range(start_step, args.steps):
+    while step < args.steps:
         if client:
             client.tick("step_begin")
         batch = stream.batch(step)
@@ -135,40 +201,46 @@ def main(argv=None):
             snap = None
         if client:
             client.tick("step_end")
+        step += 1
         loss = float(metrics["loss"])
         losses.append(loss)
-        if client and args.ckpt_every and (step + 1) % args.ckpt_every == 0:
-            fut = client.checkpoint(state, version=step + 1, snap=snap,
-                                    meta={"step": step + 1, "loss": loss})
+        if client and args.ckpt_every and step % args.ckpt_every == 0:
+            fut = client.checkpoint(state, version=step, snap=snap,
+                                    meta={"step": step, "loss": loss})
+            futures.append(fut)
             if ds and not fut.skipped:
-                ds.record(step + 1, metrics={"loss": loss})
-            print(f"step {step+1}: loss={loss:.4f} "
-                  f"ckpt_blocking={fut.results.get('app_blocking_s', 0)*1e3:.1f}ms"
+                ds.record(step, metrics={"loss": loss})
+            blocking_ms = fut.results.get("app_blocking_s", 0) * 1e3
+            print(f"step {step}: loss={loss:.4f} "
+                  f"ckpt_blocking={blocking_ms:.1f}ms"
                   f"{' (skipped)' if fut.skipped else ''}")
-        elif (step + 1) % 10 == 0:
-            print(f"step {step+1}: loss={loss:.4f}")
+        elif step % 10 == 0:
+            print(f"step {step}: loss={loss:.4f}")
 
-        if args.fail_at == step + 1:
-            print(f"[failure-sim] killing node state at step {step+1}; "
+        if args.fail_at == step and client and restored_from is None:
+            print(f"[failure-sim] killing node state at step {step}; "
                   f"restarting from newest checkpoint")
-            client.wait(timeout=60)
-            template = jax.tree.map(lambda x: x, state)
-            v, restored = client.restart_latest(template)
-            assert v is not None, "no restorable checkpoint!"
-            state = restored
-            print(f"[failure-sim] recovered at v{v}")
+            _settle(futures, SETTLE_TIMEOUT_S)
+            v, restored = client.restart_latest(state)
+            if v is None:
+                raise RuntimeError(
+                    f"failure at step {step}: no restorable checkpoint "
+                    f"({client.restart_diagnostics})")
+            state, step, restored_from = restored, v, v
+            print(f"[failure-sim] recovered at v{v}; replaying from step {v}")
 
     dt = time.time() - t_start
-    print(f"done: {args.steps - start_step} steps in {dt:.1f}s "
-          f"({(args.steps - start_step) / max(dt, 1e-9):.2f} steps/s); "
-          f"loss {losses[0]:.4f} -> {losses[-1]:.4f}")
+    print(f"done: {len(losses)} steps in {dt:.1f}s "
+          f"({len(losses) / max(dt, 1e-9):.2f} steps/s)"
+          + (f"; loss {losses[0]:.4f} -> {losses[-1]:.4f}" if losses else ""))
+    version = None
     if client:
-        client.wait(timeout=120)
+        version = _settle(futures, SETTLE_TIMEOUT_S)
         errs = client.backend.errors() if client.backend else []
         if errs:
-            print("[veloc] backend errors:", errs[0][:400])
-        client.shutdown()
-    return losses
+            raise RuntimeError(f"checkpoint backend errors: {errs[0][:400]}")
+    return TrainRun(losses=losses, state=state, version=version,
+                    restored_from=restored_from, pipeline=pipeline)
 
 
 if __name__ == "__main__":

@@ -126,11 +126,11 @@ def _moe_sharded(cfg, expert_mode, n_model, fsdp_axes, x, router_w, wg, wu, wd):
         # optimization_barrier pins the collectives to the params' bf16
         # dtype: without it the CPU pipeline hoists its dot-promotion
         # f32 converts above the gather, doubling the modelled ICI bytes
-        wg = runtime.opt_barrier(
+        wg = jax.lax.optimization_barrier(
             jax.lax.all_gather(wg, fsdp_axes, axis=1, tiled=True))
-        wu = runtime.opt_barrier(
+        wu = jax.lax.optimization_barrier(
             jax.lax.all_gather(wu, fsdp_axes, axis=1, tiled=True))
-        wd = runtime.opt_barrier(
+        wd = jax.lax.optimization_barrier(
             jax.lax.all_gather(wd, fsdp_axes, axis=2, tiled=True))
     if expert_mode:
         rank = jax.lax.axis_index("model")
@@ -142,7 +142,7 @@ def _moe_sharded(cfg, expert_mode, n_model, fsdp_axes, x, router_w, wg, wu, wd):
                        e_start=0, e_count=cfg.moe.num_experts, n_model=n_model)
     # cast before the combine so the collective moves compute-dtype bytes
     # (barrier stops the convert being hoisted past the psum)
-    return jax.lax.psum(runtime.opt_barrier(y.astype(cdt(cfg))),
+    return jax.lax.psum(jax.lax.optimization_barrier(y.astype(cdt(cfg))),
                         "model")
 
 
@@ -175,7 +175,7 @@ def apply_moe(p, cfg, x):
         w_spec = (P(None, fs, "model"), P(None, fs, "model"),
                   P(None, "model", fs))
 
-    fn = runtime.shard_map(
+    fn = jax.shard_map(
         partial(_moe_sharded, cfg, expert_mode, n_model, tuple(fsdp_axes)),
         mesh=mesh,
         in_specs=(P(dp, None), P(None, None)) + w_spec,

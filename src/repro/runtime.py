@@ -1,4 +1,5 @@
-"""Ambient runtime context: the active device mesh.
+"""Ambient runtime context: the active device mesh, and the persistent
+compilation cache.
 
 Model code (notably the MoE layer, which uses an explicit ``shard_map``
 collective schedule) consults :func:`get_mesh`.  Smoke tests and single-device
@@ -8,7 +9,9 @@ collectives.
 from __future__ import annotations
 
 import contextlib
+import os
 import threading
+from pathlib import Path
 from typing import Optional
 
 import jax
@@ -30,42 +33,27 @@ def use_mesh(mesh: Optional[jax.sharding.Mesh]):
         _state.mesh = prev
 
 
-@jax.custom_vjp
-def opt_barrier(x):
-    """Differentiable ``optimization_barrier``: older jax releases have no
-    AD rule for the primitive; its transpose is the barrier itself, so a
-    custom_vjp reproduces the native rule everywhere."""
-    return jax.lax.optimization_barrier(x)
-
-
-def _opt_barrier_fwd(x):
-    return jax.lax.optimization_barrier(x), None
-
-
-def _opt_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-opt_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compat shard_map: ``jax.shard_map`` on newer jax, the
-    experimental one (with its ``check_rep`` spelling of check_vma) on
-    older releases."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-    return legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=check_vma)
-
-
 def data_axes(mesh: Optional[jax.sharding.Mesh] = None) -> tuple[str, ...]:
     """The batch/FSDP axes present in the mesh ('pod' first when multi-pod)."""
     mesh = mesh or get_mesh()
     if mesh is None:
         return ()
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+
+
+#: JAX's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR`` is
+#: not set: one fixed directory inside the checkout (the path is part of
+#: what makes a later run find the entries, so it never varies per run).
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call before the first
+    compile.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+    itself and that directory is the cache; otherwise the cache is
+    ``DEFAULT_COMPILE_CACHE``.  Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)

@@ -1,4 +1,5 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs ref.py oracles."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -15,6 +16,17 @@ from repro.kernels.quantize import dequantize_pallas, quantize_pallas
 from repro.kernels.xor_parity import xor_pair_pallas, xor_reduce_pallas
 
 RNG = np.random.default_rng(42)
+
+
+def test_ops_refuse_backends_other_than_cpu_and_tpu(monkeypatch):
+    """Kernels are interpreted on the CPU backend and compiled on a TPU;
+    on any other backend they raise instead of silently interpreting."""
+    assert ops.interpret_mode() is True  # the test suite runs on CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops.digest(b"some bytes")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops.xor_reduce(np.zeros((2, 8), np.uint32))
 
 
 # ---------------------------------------------------------------------------
