@@ -1,0 +1,11 @@
+"""mfu.train: model FLOPs of the window's steps per second (6 x non-embedding
+parameters x tokens/s, ``harness.flops``) over the chip's bf16 peak.  Moves
+tokens_per_s."""
+from harness import readings
+
+
+def read(run):
+    p = readings.peak(run, "bf16_flops")
+    if p is None or not run.get("tokens_per_s"):
+        return None
+    return 100.0 * run["flops_per_token"] * run["tokens_per_s"] / p

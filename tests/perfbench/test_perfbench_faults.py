@@ -1,0 +1,63 @@
+"""Each fault a cell can have, planted under a whole run at a size the CPU
+holds, turns ``correct`` false: a step that returns its state unchanged,
+half of the batch left out of the loss, a stored checkpoint with a byte
+changed where it is written, a restore that hands back an altered state,
+and a restore in bfloat16 (the resume cell's lower-precision control).
+A cell on one chip has no exchange between chips to leave out.  And the
+training cells' control, the reference in fp8 in the step's place, fails
+the cell's committed limits."""
+from __future__ import annotations
+
+import pytest
+
+import perfbench_tiny as tiny
+
+from harness import faults, spec
+
+
+def _tiny(cell):
+    tiny.shrink(cell, compute_dtype="float32", limits=tiny.TINY_LIMITS)
+
+
+@pytest.fixture(scope="module")
+def root(tmp_path_factory):
+    return tiny.checkout(tmp_path_factory.mktemp("bench"))
+
+
+@pytest.mark.parametrize("workload,fault", [
+    ("phi3-train-async-full", "state_unchanged"),
+    ("phi3-train-async-full", "half_batch"),
+    ("phi3-train-async-full", "stored_byte_flipped"),
+    ("phi3-train-nockpt", "state_unchanged"),
+    ("phi3-train-nockpt", "half_batch"),
+    ("phi3-resume-restart", "restored_element_altered"),
+    ("phi3-resume-restart", "restored_bf16"),
+    ("phi3-resume-restart", "stored_byte_flipped"),
+])
+def test_planted_fault_is_not_correct(root, workload, fault):
+    with tiny.jax_cache_config(), faults.FAULTS[fault]():
+        rc, res, err = tiny.run(root, workload, hook=_tiny)
+    assert rc == 0, err[-3000:]
+    assert res["correct"] is False, res["checks"]
+    failing = [k for k, c in res["checks"].items()
+               if c["value"] is None or c["value"] > c["limit"]]
+    assert failing
+
+
+@pytest.mark.parametrize("workload", ["phi3-train-async-full",
+                                      "phi3-train-nockpt"])
+def test_fp8_control_fails_the_committed_limits(root, workload):
+    committed = spec.load_cell(workload, root).limits
+    seen = {}
+
+    def hook(cell):
+        tiny.shrink(cell)
+        seen["limits"] = dict(cell.limits)
+
+    with tiny.jax_cache_config(), faults.fp8_control():
+        rc, res, err = tiny.run(root, workload, hook=hook)
+    assert rc == 0, err[-3000:]
+    assert seen["limits"] == committed
+    assert res["correct"] is False, res["checks"]
+    assert any(res["checks"][k]["value"] > lim
+               for k, lim in committed.items()), res["checks"]
