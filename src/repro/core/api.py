@@ -33,12 +33,14 @@ from typing import Any, Callable, Optional, Union
 from repro.core import concurrency
 from repro.core import format as fmt
 from repro.core.backend import ActiveBackend, RateLimiter
-from repro.core.capture import (DeviceDeltaCapture, iter_host_regions,
-                                snapshot_device, tree_from_regions)
+from repro.core.capture import (DeviceDeltaCapture, host_state_bytes,
+                                iter_host_regions, snapshot_device,
+                                tree_from_regions)
 from repro.core.future import CheckpointFuture
 from repro.core.modules import CheckpointContext
 from repro.core.phases import EMAPhasePredictor, GRUPhasePredictor
 from repro.core.pipeline import ModuleSpec, PipelineSpec
+from repro.core.spans import ckpt_id, span
 from repro.core.storage import (RollingBatch, StorageTier, TierSpec,
                                 TierTopology, WriteBatch,
                                 default_external_specs, default_node_specs,
@@ -2299,15 +2301,18 @@ class VelocClient:
         caller passes the fused-capture ``snap``).  Everything else drains in
         the backend; track it through the returned ``CheckpointFuture``."""
         t0 = time.monotonic()
-        if snap is None:
-            snap = snapshot_device(state) if device_snapshot else state
-        cap = self.device_capture
-        if self.spec.mode == "async":
-            regions: Any = lambda: list(iter_host_regions(
-                snap, device_delta=cap))
-        else:
-            regions = list(iter_host_regions(snap, device_delta=cap))
-        fut = self._submit(regions, version, defensive=defensive, meta=meta)
+        with span("checkpoint", ckpt=ckpt_id(self.name, version, self.rank)):
+            if snap is None:
+                snap = snapshot_device(state) if device_snapshot else state
+            cap = self.device_capture
+
+            def d2h():
+                with span("d2h", bytes=host_state_bytes(snap)):
+                    return list(iter_host_regions(snap, device_delta=cap))
+
+            regions: Any = d2h if self.spec.mode == "async" else d2h()
+            fut = self._submit(regions, version, defensive=defensive,
+                               meta=meta)
         fut.results["app_blocking_s"] = time.monotonic() - t0
         return fut
 
@@ -2418,20 +2423,25 @@ class VelocClient:
         from repro.core import restart
 
         self.restart_diagnostics = []
-        plan = restart.plan_restore(self.cluster, self.name)
-        found = plan.candidates
-        for cand in found:
-            try:
-                regions = restart.load_rank_regions(
-                    self.cluster, self.name, cand["version"], self.rank,
-                    distance=self._partner_distance, plan=plan)
-                state = tree_from_regions(template, regions, shardings)
-                return cand["version"], state
-            except Exception as e:  # noqa: BLE001 — fall back a level/version
-                self.restart_diagnostics.append({
-                    "version": cand["version"], "level": cand.get("level"),
-                    "error": f"{type(e).__name__}: {e}"})
-                continue
+        with span("restore", restore=f"{self.name}:{self.rank}"):
+            with span("restore.plan"):
+                plan = restart.plan_restore(self.cluster, self.name)
+            found = plan.candidates
+            for cand in found:
+                try:
+                    with span("restore.load", version=cand["version"]):
+                        regions = restart.load_rank_regions(
+                            self.cluster, self.name, cand["version"],
+                            self.rank, distance=self._partner_distance,
+                            plan=plan)
+                    state = tree_from_regions(template, regions, shardings)
+                    return cand["version"], state
+                except Exception as e:  # noqa: BLE001 — fall back a level/version
+                    self.restart_diagnostics.append({
+                        "version": cand["version"],
+                        "level": cand.get("level"),
+                        "error": f"{type(e).__name__}: {e}"})
+                    continue
         for d in getattr(self.cluster, "segment_diagnostics", []):
             self.restart_diagnostics.append({
                 "version": None, "level": "segment",

@@ -27,6 +27,7 @@ import numpy as np
 from repro.core import concurrency
 from repro.core import delta as dlt
 from repro.core.format import Region
+from repro.core.spans import span
 from repro.kernels import ops as kops
 
 
@@ -294,33 +295,35 @@ def tree_from_regions(template, regions: dict[str, np.ndarray],
                       shardings=None):
     """Rebuild a pytree from {path: array}; device_put with shardings when
     given (restart path)."""
-    leaves_p = jax.tree_util.tree_leaves_with_path(template)
-    treedef = jax.tree.structure(template)
-    flat_shard = None if shardings is None else jax.tree.leaves(shardings)
-    out = []
-    for i, (path, leaf) in enumerate(leaves_p):
-        name = _path_str(path)
-        if name in regions:
-            arr = regions[name]
-        else:
-            # reassemble from per-shard pieces ("name@start0,start1,...")
-            prefix = name + "@"
-            pieces = {k: v for k, v in regions.items() if k.startswith(prefix)}
-            if not pieces:
-                raise KeyError(f"region {name!r} missing from checkpoint")
-            shape = leaf.shape if hasattr(leaf, "shape") else np.shape(leaf)
-            arr = np.zeros(shape, dtype=pieces[next(iter(pieces))].dtype)
-            for k, piece in pieces.items():
-                suffix = k[len(prefix):]
-                starts = tuple(int(s) for s in suffix.split(",")) if suffix \
-                    else ()
-                sl = tuple(slice(s, s + d) for s, d in zip(starts, piece.shape))
-                arr[sl] = piece
-        want_dtype = leaf.dtype if hasattr(leaf, "dtype") else np.asarray(leaf).dtype
-        arr = np.asarray(arr).astype(want_dtype, copy=False).reshape(
-            leaf.shape if hasattr(leaf, "shape") else np.shape(leaf))
-        if flat_shard is not None:
-            out.append(jax.device_put(arr, flat_shard[i]))
-        else:
-            out.append(jnp.asarray(arr))
-    return jax.tree.unflatten(treedef, out)
+    with span("restore.place"):
+        leaves_p = jax.tree_util.tree_leaves_with_path(template)
+        treedef = jax.tree.structure(template)
+        flat_shard = None if shardings is None else jax.tree.leaves(shardings)
+        out = []
+        for i, (path, leaf) in enumerate(leaves_p):
+            name = _path_str(path)
+            if name in regions:
+                arr = regions[name]
+            else:
+                # reassemble from per-shard pieces ("name@start0,start1,...")
+                prefix = name + "@"
+                pieces = {k: v for k, v in regions.items() if k.startswith(prefix)}
+                if not pieces:
+                    raise KeyError(f"region {name!r} missing from checkpoint")
+                shape = leaf.shape if hasattr(leaf, "shape") else np.shape(leaf)
+                arr = np.zeros(shape, dtype=pieces[next(iter(pieces))].dtype)
+                for k, piece in pieces.items():
+                    suffix = k[len(prefix):]
+                    starts = tuple(int(s) for s in suffix.split(",")) if suffix \
+                        else ()
+                    sl = tuple(slice(s, s + d) for s, d in zip(starts, piece.shape))
+                    arr[sl] = piece
+            want_dtype = leaf.dtype if hasattr(leaf, "dtype") else np.asarray(leaf).dtype
+            arr = np.asarray(arr).astype(want_dtype, copy=False).reshape(
+                leaf.shape if hasattr(leaf, "shape") else np.shape(leaf))
+            with span("restore.device_put", bytes=arr.nbytes):
+                if flat_shard is not None:
+                    out.append(jax.device_put(arr, flat_shard[i]))
+                else:
+                    out.append(jnp.asarray(arr))
+        return jax.tree.unflatten(treedef, out)

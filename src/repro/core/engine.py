@@ -20,6 +20,7 @@ from typing import Optional
 from repro.core.backend import ActiveBackend, AdmissionError
 from repro.core.future import CheckpointError, CheckpointFuture
 from repro.core.modules import CheckpointContext, Module
+from repro.core.spans import ckpt_id, span
 
 
 def _payload_estimate(ctx: CheckpointContext) -> int:
@@ -60,7 +61,8 @@ class Engine:
         for m in mods:
             if not m.enabled:
                 continue
-            status = m.process(ctx)
+            with span(m.name):
+                status = m.process(ctx)
             ctx.results[f"{m.name}.status"] = status
             if ctx.skipped:
                 break
@@ -95,6 +97,14 @@ class Engine:
 
     def submit(self, ctx: CheckpointContext,
                future: Optional[CheckpointFuture] = None) -> CheckpointContext:
+        if self.backend is None:   # the whole pipeline on the caller
+            with span("pipeline",
+                      ckpt=ckpt_id(ctx.name, ctx.version, ctx.rank)):
+                return self._submit(ctx, future)
+        return self._submit(ctx, future)
+
+    def _submit(self, ctx: CheckpointContext,
+                future: Optional[CheckpointFuture]) -> CheckpointContext:
         ctx.engine = self
         front = [m for m in self.modules if m.priority <= self.blocking_cut]
         rest = [m for m in self.modules if m.priority > self.blocking_cut]
@@ -121,7 +131,9 @@ class Engine:
         else:
             def run_rest():
                 try:
-                    self._run(rest, ctx, future)
+                    with span("pipeline",
+                              ckpt=ckpt_id(ctx.name, ctx.version, ctx.rank)):
+                        self._run(rest, ctx, future)
                 except Exception as e:  # noqa: BLE001 — routed + re-raised
                     if future is not None:
                         future._finish(e)

@@ -26,6 +26,7 @@ from repro.core import concurrency
 from repro.core import delta as dlt
 from repro.core import erasure, format as fmt
 from repro.core.pipeline import register_module
+from repro.core.spans import span
 from repro.core.storage import pick_tier
 from repro.kernels import ops as kops
 
@@ -458,17 +459,18 @@ class FlushModule(Module):
                 limiters.append(lane)
         limiters.append(ctx.cluster.rate_limiter)
         gate = ctx.cluster.phase_gate
-        if nbytes <= self.chunk_bytes:
-            for lim in limiters:
-                lim.acquire(nbytes)
-            return
-        for off in range(0, nbytes, self.chunk_bytes):
-            for lim in limiters:
-                lim.acquire(min(self.chunk_bytes, nbytes - off))
-            if gate is not None:
-                w = gate()
-                if w > 0:
-                    time.sleep(min(w, 0.5))
+        with span("l3.pace"):
+            if nbytes <= self.chunk_bytes:
+                for lim in limiters:
+                    lim.acquire(nbytes)
+                return
+            for off in range(0, nbytes, self.chunk_bytes):
+                for lim in limiters:
+                    lim.acquire(min(self.chunk_bytes, nbytes - off))
+                if gate is not None:
+                    w = gate()
+                    if w > 0:
+                        time.sleep(min(w, 0.5))
 
     def process(self, ctx):
         target = ctx.cluster.aggregate_target()

@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.core import erasure
 from repro.core import format as fmt
+from repro.core.spans import span
 
 _LEVEL_ORDER = {"L1": 0, "L2": 1, "L3": 2}
 
@@ -408,6 +409,13 @@ def fetch_shard_any_level(cluster, name: str, version: int, rank: int,
     return None
 
 
+def _decode(reader: fmt.ShardReader, name: str,
+            base: Optional[np.ndarray] = None) -> np.ndarray:
+    """One region of a fetched shard as an array, its digest verified."""
+    with span("restore.decode"):
+        return reader.read(name, base=base)
+
+
 #: Hard ceiling on delta-chain walks: defends against cyclic or corrupted
 #: parent links; real chains are bounded by DeltaModule's ``max_chain``.
 MAX_CHAIN_DEPTH = 64
@@ -457,7 +465,7 @@ def _load_rank_walk(cluster, name: str, version: int, rank: int,
     reader = fmt.ShardReader(blob)
     delta_names = set(reader.delta_regions())
     if not delta_names:
-        return {n: reader.read(n) for n in reader.region_names}
+        return {n: _decode(reader, n) for n in reader.region_names}
     if _depth >= MAX_CHAIN_DEPTH:
         raise IOError(f"delta chain exceeds {MAX_CHAIN_DEPTH} links at "
                       f"v{version} (cyclic or corrupt parent metadata)")
@@ -474,9 +482,9 @@ def _load_rank_walk(cluster, name: str, version: int, rank: int,
             if n not in base:
                 raise IOError(f"delta region {n!r} of v{version} missing "
                               f"from parent v{parent}")
-            out[n] = reader.read(n, base=base[n])
+            out[n] = _decode(reader, n, base[n])
         else:
-            out[n] = reader.read(n)
+            out[n] = _decode(reader, n)
     return out
 
 
@@ -527,7 +535,7 @@ def load_rank_regions(cluster, name: str, version: int, rank: int,
             break
     if base_found:
         prev_v, base_reader = hops.pop()
-        out = {n: base_reader.read(n) for n in base_reader.region_names}
+        out = {n: _decode(base_reader, n) for n in base_reader.region_names}
     else:
         # metadata called the deepest hop the full base but this RANK's
         # blob is still a delta (ranks go full independently; links can
@@ -549,9 +557,9 @@ def load_rank_regions(cluster, name: str, version: int, rank: int,
                 if n not in out:
                     raise IOError(f"delta region {n!r} of v{v} missing "
                                   f"from parent v{prev_v}")
-                nxt[n] = reader.read(n, base=out[n])
+                nxt[n] = _decode(reader, n, out[n])
             else:
-                nxt[n] = reader.read(n)
+                nxt[n] = _decode(reader, n)
         out = nxt
         prev_v = v
     return out

@@ -30,8 +30,17 @@ from typing import Callable, Optional
 
 from repro.core import concurrency
 from repro.core.concurrency import TrackedLock
+from repro.core.spans import span
 
 _UNESCAPE_RE = re.compile(r"_[us]")
+
+
+def _fsync(f) -> None:
+    """Flush an open file and wait until it is durable: the part of a
+    tier write that waits on the device."""
+    with span("fsync"):
+        f.flush()
+        os.fsync(f.fileno())
 
 
 def escape_key(key: str) -> str:
@@ -198,7 +207,8 @@ class StorageTier:
         concurrency.note_tier_io(self, "get")
         t0 = time.perf_counter()
         try:
-            blob = self._get(key)
+            with span("tier.get", tier=self.info.name):
+                blob = self._get(key)
         except BaseException:
             self._note_get(time.perf_counter() - t0, None, error=True)
             raise
@@ -285,8 +295,7 @@ class FileTier(StorageTier):
             tmp = self._path(key) + ".tmp"
             with open(tmp, "wb") as f:
                 f.write(data)
-                f.flush()
-                os.fsync(f.fileno())
+                _fsync(f)
             os.replace(tmp, self._path(key))  # atomic publish
         finally:
             self._exit()
@@ -419,8 +428,7 @@ class KVTier(StorageTier):
                 with open(tmp, "wb") as fh:
                     for key, data in records:
                         fh.write(fmt.encode_log_record(key, data))
-                    fh.flush()
-                    os.fsync(fh.fileno())
+                    _fsync(fh)
                 os.replace(tmp, log)
                 self._log_records = len(records)
 
@@ -433,8 +441,7 @@ class KVTier(StorageTier):
                 self._log_f = open(
                     os.path.join(self._journal, _KV_LOG_FILE), "ab")
             self._log_f.write(fmt.encode_log_record(key, data))
-            self._log_f.flush()
-            os.fsync(self._log_f.fileno())
+            _fsync(self._log_f)
             self._log_records += 1
             want_compact = self._compact_every and \
                 self._log_records >= self._compact_every
@@ -457,8 +464,7 @@ class KVTier(StorageTier):
                                       meta={"kind": "kv-journal"})
             with open(snap + ".tmp", "wb") as f:
                 f.write(blob)
-                f.flush()
-                os.fsync(f.fileno())
+                _fsync(f)
             os.replace(snap + ".tmp", snap)  # atomic publish
             # absorb legacy per-key files BEFORE truncating the log: if we
             # crash in between, the log (with any tombstones for legacy

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import span
 from repro.kernels import checksum as _ck
 from repro.kernels import quantize as _qz
 from repro.kernels import xor_parity as _xp
@@ -153,10 +154,16 @@ def fold_digest(chunks: np.ndarray, n_words: int) -> str:
     return f"{int(h1):08x}{int(h2):08x}{int(n_words):08x}"
 
 
+def _nbytes(buf) -> int:
+    n = getattr(buf, "nbytes", None)   # arrays and memoryviews
+    return len(buf) if n is None else n
+
+
 def digest(buf: bytes | np.ndarray) -> str:
     """Hex digest of a byte buffer (chunk checksums folded host-side)."""
-    words = bytes_to_u32(buf)
-    return fold_digest(fletcher_chunks(words), len(words))
+    with span("digest", bytes=_nbytes(buf)):
+        words = bytes_to_u32(buf)
+        return fold_digest(fletcher_chunks(words), len(words))
 
 
 def chunk_digests(blobs) -> list[str]:
@@ -169,27 +176,29 @@ def chunk_digests(blobs) -> list[str]:
     chunks that is 1 dispatch, not N.  Byte-identical output to per-buffer
     ``digest``."""
     blobs = list(blobs)
-    out: list = [None] * len(blobs)
-    words_of: list = [None] * len(blobs)
-    groups: dict[int, list[int]] = {}
-    for j, b in enumerate(blobs):
-        w = bytes_to_u32(b)
-        if w.shape[0] == 0:
-            out[j] = fold_digest(np.zeros((0, 2), np.uint32), 0)
-            continue
-        words_of[j] = w
-        groups.setdefault(-(-w.shape[0] // _ck.CHUNK_WORDS), []).append(j)
-    for rows, members in groups.items():
-        span = rows * _ck.CHUNK_WORDS
-        stacked = np.zeros(len(members) * span, np.uint32)
-        for slot, j in enumerate(members):
-            w = words_of[j]
-            stacked[slot * span:slot * span + w.shape[0]] = w
-        table = fletcher_chunks(stacked)
-        for slot, j in enumerate(members):
-            out[j] = fold_digest(table[slot * rows:(slot + 1) * rows],
-                                 words_of[j].shape[0])
-    return out
+    with span("digest", bytes=sum(_nbytes(b) for b in blobs)):
+        out: list = [None] * len(blobs)
+        words_of: list = [None] * len(blobs)
+        groups: dict[int, list[int]] = {}
+        for j, b in enumerate(blobs):
+            w = bytes_to_u32(b)
+            if w.shape[0] == 0:
+                out[j] = fold_digest(np.zeros((0, 2), np.uint32), 0)
+                continue
+            words_of[j] = w
+            rows = -(-w.shape[0] // _ck.CHUNK_WORDS)
+            groups.setdefault(rows, []).append(j)
+        for rows, members in groups.items():
+            width = rows * _ck.CHUNK_WORDS
+            stacked = np.zeros(len(members) * width, np.uint32)
+            for slot, j in enumerate(members):
+                w = words_of[j]
+                stacked[slot * width:slot * width + w.shape[0]] = w
+            table = fletcher_chunks(stacked)
+            for slot, j in enumerate(members):
+                out[j] = fold_digest(table[slot * rows:(slot + 1) * rows],
+                                     words_of[j].shape[0])
+        return out
 
 
 # ---------------------------------------------------------------------------

@@ -230,8 +230,7 @@ def _train(args, cfg, key, stream, client, pipeline) -> TrainRun:
             print(f"[failure-sim] recovered at v{v}; replaying from step {v}")
 
     dt = time.time() - t_start
-    print(f"done: {len(losses)} steps in {dt:.1f}s "
-          f"({len(losses) / max(dt, 1e-9):.2f} steps/s)"
+    print(f"done: {len(losses)} steps in {dt:.1f}s, compiling included"
           + (f"; loss {losses[0]:.4f} -> {losses[-1]:.4f}" if losses else ""))
     version = None
     if client:
