@@ -38,6 +38,10 @@ def interpret_mode() -> bool:
 #: assert batching actually collapses per-chunk dispatches into one).
 KERNEL_DISPATCHES = {"checksum": 0, "blockhash": 0, "gather": 0, "xor": 0}
 
+#: Lifetime host-byte counters of ``digest``: bytes handed to the device as
+#: a view of the caller's buffer, and bytes copied on the host first.
+DIGEST_HOST_BYTES = {"viewed": 0, "copied": 0}
+
 
 def _pad_last(x, total: int):
     """Zero-pad the last axis of ``x`` to ``total``: on the host for a
@@ -51,11 +55,22 @@ def _pad_last(x, total: int):
         else jnp.pad(x, widths)
 
 
-def bytes_to_u32(buf: bytes | np.ndarray) -> np.ndarray:
+def _is_contiguous(buf) -> bool:
+    """Whether ``_byte_view`` reads ``buf`` in place, without a copy."""
+    return isinstance(buf, (bytes, bytearray, memoryview)) or (
+        isinstance(buf, np.ndarray) and buf.flags.c_contiguous)
+
+
+def _byte_view(buf: bytes | np.ndarray) -> np.ndarray:
+    """The bytes of ``buf`` as a flat uint8 array: a view when
+    ``_is_contiguous(buf)``, else a contiguous copy."""
     if isinstance(buf, (bytes, bytearray, memoryview)):
-        a = np.frombuffer(buf, dtype=np.uint8)
-    else:
-        a = np.ascontiguousarray(buf).reshape(-1).view(np.uint8)
+        return np.frombuffer(buf, dtype=np.uint8)
+    return np.ascontiguousarray(buf).reshape(-1).view(np.uint8)
+
+
+def bytes_to_u32(buf: bytes | np.ndarray) -> np.ndarray:
+    a = _byte_view(buf)
     pad = (-a.size) % 4
     if pad:
         a = np.concatenate([a, np.zeros(pad, np.uint8)])
@@ -87,8 +102,9 @@ def xor_reduce(x) -> np.ndarray:
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def _checksum_j(x, interpret=True):
-    return _ck.checksum_pallas(x, interpret=interpret)
+def _checksum_j(*parts, interpret=True):
+    """The checksum tables of one or more row tilings, in one program."""
+    return tuple(_ck.checksum_pallas(x, interpret=interpret) for x in parts)
 
 
 def padded_rows(rows: int) -> int:
@@ -107,7 +123,7 @@ def _row_tiling(words, chunk: int):
     buffer length costs no device compile."""
     rows = -(-words.shape[0] // chunk)
     words = _pad_last(words, padded_rows(rows) * chunk)
-    return jnp.asarray(words).reshape(-1, chunk), rows
+    return jnp.asarray(words.reshape(-1, chunk)), rows
 
 
 def fletcher_chunks(words: jax.Array | np.ndarray,
@@ -117,7 +133,8 @@ def fletcher_chunks(words: jax.Array | np.ndarray,
         return np.zeros((0, 2), np.uint32)
     KERNEL_DISPATCHES["checksum"] += 1
     tiles, rows = _row_tiling(words, chunk)
-    return np.asarray(_checksum_j(tiles, interpret=interpret_mode()))[:rows]
+    table, = _checksum_j(tiles, interpret=interpret_mode())
+    return np.asarray(table)[:rows]
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -159,11 +176,44 @@ def _nbytes(buf) -> int:
     return len(buf) if n is None else n
 
 
+#: bytes of one whole 64-row tile of checksum rows (512 KiB)
+_TILE_BYTES = 4 * _ck.BLOCK_ROWS * _ck.CHUNK_WORDS
+
+
 def digest(buf: bytes | np.ndarray) -> str:
-    """Hex digest of a byte buffer (chunk checksums folded host-side)."""
-    with span("digest", bytes=_nbytes(buf)):
-        words = bytes_to_u32(buf)
-        return fold_digest(fletcher_chunks(words), len(words))
+    """Hex digest of a byte buffer (chunk checksums folded host-side).
+
+    The buffer's whole 64-row tiles go to the device as a view of its
+    bytes; only the tail after them (under one tile) is copied, into a
+    zeroed tiling, so its last partial word reads little-endian with zero
+    fill.  Both tilings are checksummed in one program.  Chunks are
+    independent and zero rows fold as the identity (``fold_digest``), so
+    the cut leaves the digest as if the whole zero-padded buffer were
+    checksummed at once."""
+    nbytes = _nbytes(buf)
+    body = nbytes // _TILE_BYTES * _TILE_BYTES
+    copied = nbytes - body + (0 if _is_contiguous(buf) else nbytes)
+    with span("digest", bytes=nbytes, copied=copied):
+        u8 = _byte_view(buf)
+        parts = []
+        if body:
+            parts.append(u8[:body].view(np.uint32)
+                         .reshape(-1, _ck.CHUNK_WORDS))
+        if nbytes > body:
+            rows = -(-(nbytes - body) // (4 * _ck.CHUNK_WORDS))
+            tail = np.zeros((padded_rows(rows), _ck.CHUNK_WORDS), np.uint32)
+            tail.view(np.uint8).reshape(-1)[:nbytes - body] = u8[body:]
+            parts.append(tail)
+        DIGEST_HOST_BYTES["viewed"] += body
+        DIGEST_HOST_BYTES["copied"] += copied
+        n_words = -(-nbytes // 4)
+        if not parts:
+            return fold_digest(np.zeros((0, 2), np.uint32), n_words)
+        KERNEL_DISPATCHES["checksum"] += 1
+        tables = _checksum_j(*map(jnp.asarray, parts),
+                             interpret=interpret_mode())
+        return fold_digest(np.concatenate([np.asarray(t) for t in tables]),
+                           n_words)
 
 
 def chunk_digests(blobs) -> list[str]:

@@ -12,6 +12,7 @@ from helpers import CorruptingTier, FlakyTier, StallingTier, \
 from repro.core import Cluster, VelocClient, VelocConfig
 from repro.core import format as fmt
 from repro.core import restart as rst
+from repro.kernels import ops as kops
 
 
 def _cluster(tmp_path, nranks, **kw):
@@ -117,18 +118,46 @@ def test_restart_l3_as_last_resort(tmp_path):
         assert (regs["w"] == r).all()
 
 
+def _odd_multi_tile_states(nranks):
+    """Shards of three whole 512 KiB checksum tiles and an odd tail: the
+    digests view the tiles in place and pad only the tail, whose last
+    word is partial."""
+    rng = np.random.default_rng(17)
+    return [{"w": rng.integers(0, 256, 3 * 512 * 1024 + 1001, np.uint8),
+             "step": np.asarray(7 + r)} for r in range(nranks)]
+
+
 def test_corrupted_l1_is_rejected_by_digest(tmp_path):
-    """Manifest digests catch a silently-corrupting L1 read."""
-    cfg, cluster, clients = _cluster(tmp_path, 2, partner=True, xor_group=0,
+    """Manifest digests catch a silently-corrupting L1 read (its last
+    byte flipped), and the partner's copy reads back identical: for a
+    small shard, and for one of whole checksum tiles and an odd tail."""
+    _reject_corrupted_l1(tmp_path / "small", _states(2))
+    blob = _reject_corrupted_l1(tmp_path / "tiles", _odd_multi_tile_states(2))
+    assert len(blob) % 2 == 1
+
+
+def _reject_corrupted_l1(root, states):
+    cfg, cluster, clients = _cluster(root, 2, partner=True, xor_group=0,
                                      flush=False)
-    states = _states(2)
     for r, c in enumerate(clients):
         c.checkpoint(states[r], version=1, device_snapshot=False)
+    blob = cluster.fetch_shard(cfg.name, 1, 0)
+    for name, want in states[0].items():
+        assert fmt.ShardReader(blob).read(name).tobytes() == want.tobytes()
     tiers = wrap_node_tiers(cluster, 0,
                             lambda t: CorruptingTier(t, match="shard_00000"))
     regs = rst.load_rank_regions(cluster, cfg.name, 1, 0)
-    assert (regs["w"] == 0).all()
+    for name, want in states[0].items():
+        assert regs[name].tobytes() == want.tobytes()
     assert any(t.corrupted_gets for t in tiers)  # fallback actually exercised
+    digest, = {m["shard_digests"][0] for m in cluster.manifests(cfg.name)
+               if m["version"] == 1}
+    assert rst.fetch_shard_any_level(cluster, cfg.name, 1, 0,
+                                     expected_digest=digest) == blob
+    torn = bytearray(blob)
+    torn[-1] ^= 0xFF
+    assert kops.digest(bytes(torn)) != digest
+    return blob
 
 
 # ---------------------------------------------------------------------------

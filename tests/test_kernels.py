@@ -11,7 +11,8 @@ from hypothesis import given, settings  # noqa: E402
 from hypothesis import strategies as st  # noqa: E402
 
 from repro.kernels import ops, ref
-from repro.kernels.checksum import blockhash_pallas, checksum_pallas
+from repro.kernels.checksum import (CHUNK_WORDS, blockhash_pallas,
+                                    checksum_pallas)
 from repro.kernels.quantize import dequantize_pallas, quantize_pallas
 from repro.kernels.xor_parity import xor_pair_pallas, xor_reduce_pallas
 
@@ -139,6 +140,90 @@ def test_digest_detects_flip(buf, pos):
     mod[pos] ^= 0x5A
     if bytes(mod) != buf:
         assert ops.digest(bytes(mod)) != ops.digest(buf)
+
+
+#: bytes of one 64-row checksum tile: a digest views whole ones in place
+TILE = 4 * 64 * CHUNK_WORDS
+
+_checksum_rows = jax.jit(lambda x: checksum_pallas(x, interpret=True))
+
+
+def _digest_padding_everything(raw: bytes) -> str:
+    """The digest as computed before digests viewed their input: the whole
+    buffer zero-padded to whole words, then to ``padded_rows`` rows, and
+    checksummed as one tiling."""
+    a = np.frombuffer(raw, np.uint8)
+    words = np.concatenate([a, np.zeros(-a.size % 4, np.uint8)]) \
+        .view(np.uint32)
+    n_words = words.size
+    if not n_words:
+        return ops.fold_digest(np.zeros((0, 2), np.uint32), 0)
+    rows = -(-n_words // CHUNK_WORDS)
+    words = np.pad(words, (0, ops.padded_rows(rows) * CHUNK_WORDS - n_words))
+    table = _checksum_rows(jnp.asarray(words.reshape(-1, CHUNK_WORDS)))
+    return ops.fold_digest(np.asarray(table)[:rows], n_words)
+
+
+def _typed(dtype):
+    """The whole items of ``raw`` as a contiguous ndarray of ``dtype``."""
+    def make(raw):
+        size = np.dtype(dtype).itemsize
+        return np.frombuffer(raw[:len(raw) - len(raw) % size], dtype)
+    return make
+
+
+DIGEST_INPUTS = {
+    "bytes": bytes,
+    "bytearray": bytearray,
+    "memoryview": memoryview,
+    "uint8": _typed(np.uint8),
+    "float32": _typed(np.float32),
+    "bfloat16": _typed(jnp.bfloat16),
+    # every other byte of a doubled buffer: the same bytes, strided
+    "strided": lambda raw: np.frombuffer(raw, np.uint8).repeat(2)[::2],
+}
+
+
+@pytest.mark.parametrize("kind", sorted(DIGEST_INPUTS))
+@pytest.mark.parametrize("n", [0, 1, 3, 4, 5, 8191, 8192, 8193, TILE - 1,
+                               TILE, TILE + 1, TILE + 3, 2 * TILE + 7,
+                               3 * TILE])
+def test_digest_matches_the_padded_whole_buffer(n, kind):
+    """Digesting whole tiles in place and only the tail padded gives the
+    digest of the whole zero-padded buffer, for every length and input
+    type — the digests stored in shards, manifests and logs."""
+    raw = np.random.default_rng(n).integers(0, 256, n, np.uint8).tobytes()
+    buf = DIGEST_INPUTS[kind](raw)
+    if isinstance(buf, np.ndarray):
+        raw = buf.tobytes()
+    assert ops.digest(buf) == _digest_padding_everything(raw)
+
+
+def test_digest_is_pinned():
+    """A fixed buffer of two tiles and three bytes digests as it always
+    has, so no stored digest can drift."""
+    n = 2 * TILE + 3
+    buf = ((np.arange(n, dtype=np.uint64) * np.uint64(2654435761))
+           >> np.uint64(13)).astype(np.uint8)
+    assert ops.digest(buf.tobytes()) == "14f63d80d899438000040001"
+
+
+@pytest.mark.parametrize("strided", [False, True])
+def test_digest_views_whole_tiles_and_copies_the_tail(strided):
+    """3 tiles and 5 bytes: one checksum program, the tiles handed over as
+    a view, only the 5 tail bytes copied (the whole buffer once more when
+    it is not contiguous)."""
+    n = 3 * TILE + 5
+    buf = np.random.default_rng(5).integers(0, 256, n, np.uint8)
+    if strided:
+        buf = buf.repeat(2)[::2]
+    dispatches = ops.KERNEL_DISPATCHES["checksum"]
+    host = dict(ops.DIGEST_HOST_BYTES)
+    ops.digest(buf if strided else buf.tobytes())
+    assert ops.KERNEL_DISPATCHES["checksum"] == dispatches + 1
+    assert ops.DIGEST_HOST_BYTES["viewed"] - host["viewed"] == 3 * TILE
+    assert ops.DIGEST_HOST_BYTES["copied"] - host["copied"] == \
+        5 + (n if strided else 0)
 
 
 # ---------------------------------------------------------------------------

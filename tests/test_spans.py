@@ -18,6 +18,7 @@ from repro.core import (Cluster, ModuleSpec, PipelineSpec, TierTopology,
 from repro.core import format as fmt
 from repro.core.capture import host_state_bytes, iter_host_regions
 from repro.core.spans import PREFIX, span
+from repro.kernels import ops as kops
 
 STREAM = "spans"
 SAVE_MODULES = ("interval", "serialize", "l1-local", "l3-flush")
@@ -202,6 +203,21 @@ def test_an_untraced_save_is_unchanged(traced, tmp_path):
     assert blob == want and res["shard_bytes"] == len(want)
     assert set(res) == set(traced["fut"].results)
     assert traced["fut"].results["shard_bytes"] == len(want)
+
+
+def test_digest_span_counts_the_bytes_it_copied(tmp_path):
+    """``veloc.digest`` carries the buffer's bytes and the bytes the digest
+    copied on the host: the tail after its whole 512 KiB tiles."""
+    n = 2 * 512 * 1024 + 7
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        kops.digest(np.zeros(n, np.uint8).tobytes())
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    assert _one(_spans(path), "veloc.digest").stats == {"bytes": n,
+                                                        "copied": 7}
 
 
 def test_span_is_a_trace_annotation_with_the_prefix():

@@ -53,6 +53,10 @@ def _xor_case(k):
 CASES = {
     "checksum": (lambda x: ck.checksum_pallas(x, interpret=False),
                  [((512, ck.CHUNK_WORDS), jnp.uint32)]),
+    # a digest's one program: a 3.74 GB shard's whole tiles and its tail
+    "checksum_parts": (lambda b, t: ops._checksum_j(b, t, interpret=False),
+                       [((456192, ck.CHUNK_WORDS), jnp.uint32),
+                        ((16, ck.CHUNK_WORDS), jnp.uint32)]),
     "blockhash": (lambda x: ck.blockhash_pallas(x, interpret=False),
                   [((64, CHUNK_64K), jnp.uint32)]),
     "blockhash_diff": (
