@@ -34,12 +34,12 @@ from harness import cli, faults, program, refcheck, rows, spec  # noqa: E402
 from harness import weights  # noqa: E402
 
 
-def _train_readings(cell, seeds, fault=None, quant=None):
+def _train_readings(cell, seeds, fault=None, quant=None, refs=None):
     """One JSON line per seed: the program (or, with ``fault``, the program
     with the fault planted, or with ``quant``, the reference in that
-    precision) against the reference."""
-    import jax.numpy as jnp
-
+    precision) against the reference.  The program runs on the traffic's
+    layout, as the benchmark's loop runs it; ``refs`` keeps the
+    reference's readings of each seed for the next call."""
     ref = spec.reference(cell)
     mc = program.model_config(cell, ref)
     c, tr = cell.config, cell.traffic
@@ -47,41 +47,50 @@ def _train_readings(cell, seeds, fault=None, quant=None):
     shape = (tr["batch"], tr["seq_len"], mc.vocab_size)
     h = tr["optimizer"]
     names = list(weights.shapes(layout))
-    step_fn = None
-    if seeds and quant is None:
-        # the fault is planted while the step is built
-        with faults.FAULTS[fault]() if fault else contextlib.nullcontext():
-            step_fn = program.train_step(mc, tr)
-    for seed in seeds:
-        key = weights.seed_key(seed)
-        batches = [rows.tokens(seed, s, *shape)
-                   for s in range(tr["check_steps"])]
-        t0 = time.monotonic()
-        if step_fn is not None:
-            fed = iter(batches)
+    refs = {} if refs is None else refs
+    mesh = program.mesh(tr, cell.chips)
+    with program.use_mesh(mesh):
+        sh = program.state_shardings(mc, mesh, weights.state_maker(layout))
+        feed = program.feeder(mesh)
+        step_fn = None
+        if seeds and quant is None:
+            # the fault is planted while the step is built
+            with faults.FAULTS[fault]() if fault else \
+                    contextlib.nullcontext():
+                step_fn = program.train_step(mc, tr, sh)
+        for seed in seeds:
+            key = weights.seed_key(seed)
+            batches = [rows.tokens(seed, s, *shape)
+                       for s in range(tr["check_steps"])]
+            t0 = time.monotonic()
+            if step_fn is not None:
+                fed = iter(batches)
 
-            def one(state):
-                out = step_fn(state, {"tokens": jnp.asarray(next(fed))})
-                snap = out[1] if len(out) == 3 else None
-                return out[0], snap, float(out[-1]["loss"])
+                def one(state):
+                    out = step_fn(state, feed(next(fed)))
+                    snap = out[1] if len(out) == 3 else None
+                    return out[0], snap, float(out[-1]["loss"])
 
-            state = weights.state_maker(layout)(key)
-            state, snap, prog = refcheck.program_readings(
-                one, state, tr["check_steps"], h["b1"],
-                lambda: weights.params_maker(layout)(key))
-            del state, snap
-        else:
-            prog = refcheck.run_reference(ref, c, h,
-                                          weights.params_maker(layout), key,
-                                          batches, quant=quant)
-        got = refcheck.run_reference(ref, c, h, weights.params_maker(layout),
-                                     key, batches)
-        g = refcheck.gaps(prog, got)
-        g.update(_worst(prog, got, names))
-        g.update({"seed": seed, "kind": fault or quant or "program",
-                  "losses": prog["losses"], "ref_losses": got["losses"],
-                  "seconds": time.monotonic() - t0})
-        print(json.dumps(g), flush=True)
+                state = weights.state_maker(layout, sh)(key)
+                state, snap, prog = refcheck.program_readings(
+                    one, state, tr["check_steps"], h["b1"],
+                    lambda: weights.params_maker(
+                        layout, sh["params"] if sh is not None else None)(key))
+                del state, snap
+            else:
+                prog = refcheck.run_reference(
+                    ref, c, h, weights.params_maker(layout), key, batches,
+                    quant=quant)
+            if seed not in refs:
+                refs[seed] = refcheck.run_reference(
+                    ref, c, h, weights.params_maker(layout), key, batches)
+            got = refs[seed]
+            g = refcheck.gaps(prog, got)
+            g.update(_worst(prog, got, names))
+            g.update({"seed": seed, "kind": fault or quant or "program",
+                      "losses": prog["losses"], "ref_losses": got["losses"],
+                      "seconds": time.monotonic() - t0})
+            print(json.dumps(g), flush=True)
 
 
 def _worst(prog, got, names):
@@ -121,12 +130,18 @@ def main(argv=None):
     if jax.devices()[0].platform != "tpu":
         print("calibrate: no TPU", file=sys.stderr)
         return 3
+    if len(jax.devices()) < cell.chips:
+        print(f"calibrate: {cell.name} needs {cell.chips} chips",
+              file=sys.stderr)
+        return 3
     if cell.traffic["loop"] == "train":
-        _train_readings(cell, seeds(args.seeds))
-        _train_readings(cell, seeds(args.control_seeds), quant="fp8")
+        refs = {}
+        _train_readings(cell, seeds(args.seeds), refs=refs)
+        _train_readings(cell, seeds(args.control_seeds), quant="fp8",
+                        refs=refs)
         for f in args.fault:
             name, s = f.split(":")
-            _train_readings(cell, seeds(s), fault=name)
+            _train_readings(cell, seeds(s), fault=name, refs=refs)
     else:
         _whole_runs(args.workload, seeds(args.control_seeds), args.seconds,
                     "restored_bf16")

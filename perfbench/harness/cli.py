@@ -97,7 +97,7 @@ def main(argv=None, *, root: Path = spec.ROOT,
     if on_chip:
         if args.trace:
             run = dict(out.record, on_chip=True, trace=out.trace,
-                       device_kind=ctx.device_kind)
+                       device_kind=ctx.device_kind, chips=cell.chips)
             for m in cell.per_layer:
                 v = spec.metric_reader(cell, m["name"])(run)
                 if v is not None:

@@ -128,7 +128,7 @@ def fp8_control():
         seen.update(config=cell.config, ref=ref)
         return real_config(cell, ref)
 
-    def train_step(mc, traffic):
+    def train_step(mc, traffic, shardings=None):
         ref, c, h = seen["ref"], seen["config"], traffic["optimizer"]
         capture = traffic["capture"] == "fused"
 
@@ -147,14 +147,55 @@ def fp8_control():
                 jax.tree.map(lambda x: x + jnp.zeros((), x.dtype), new))
             return new, snap, {"loss": loss}
 
-        return jax.jit(step, donate_argnums=(0,))
+        return program.jit_step(step, capture, shardings)
 
     with mock.patch.object(program, "model_config", model_config), \
             mock.patch.object(program, "train_step", train_step):
         yield
 
 
+@contextlib.contextmanager
+def exchange_left_out():
+    """On a mesh, each chip takes the loss and gradient of its own rows
+    and none is exchanged: the reduction over the data axis is left out,
+    and each chip updates the shard of the state it holds with its own
+    gradient (the loss read is one chip's)."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro import runtime
+    from repro.train import optimizer, steps
+
+    def make(cfg, *, lr=3e-4, capture=False):
+        loss_fn = steps.make_loss_fn(cfg)
+
+        def local_grad(params, tokens):
+            with runtime.use_mesh(None):   # inside, every axis is manual
+                return jax.value_and_grad(loss_fn)(params,
+                                                   {"tokens": tokens})
+
+        def train_step(state, batch):
+            mesh = runtime.get_mesh()
+            local = jax.shard_map(
+                local_grad, mesh=mesh, in_specs=(P(), P(runtime.data_axes(mesh))),
+                out_specs=P(), check_vma=False)
+            loss, grads = local(state["params"], batch["tokens"])
+            params, opt, metrics = optimizer.adamw_update(
+                grads, state["opt"], state["params"], lr=lr)
+            metrics["loss"] = loss
+            new = {"params": params, "opt": opt}
+            if not capture:
+                return new, metrics
+            snap = jax.lax.optimization_barrier(
+                jax.tree.map(lambda x: x + jnp.zeros((), x.dtype), new))
+            return new, snap, metrics
+        return train_step
+
+    with mock.patch.object(steps, "make_train_step", make):
+        yield
+
+
 FAULTS = {"state_unchanged": state_unchanged, "half_batch": half_batch,
+          "exchange_left_out": exchange_left_out,
           "stored_byte_flipped": stored_byte_flipped,
           "restored_bf16": restored_bf16,
           "restored_element_altered": restored_element_altered,

@@ -4,11 +4,18 @@
 ``model_flops``): 6 x non-embedding parameters x tokens for a training
 step, where the embedding and the output head (paths holding ``emb`` or
 ``lm_head``) do not count.  Attention's score arithmetic is not counted
-either, so ``mfu`` understates the work of long sequences.
+either, so ``mfu`` understates the work of long sequences.  A parameter
+counts by the share of it that a token uses: all of it, unless the
+reference states a smaller share (``active_shares``), as for routed
+experts, of which a token uses its top-k.
 """
 from __future__ import annotations
 
 import math
+
+
+def _embedding(path: str) -> bool:
+    return "emb" in path or "lm_head" in path
 
 
 def param_counts(shapes: dict) -> dict:
@@ -17,11 +24,17 @@ def param_counts(shapes: dict) -> dict:
     for path, shape in shapes.items():
         n = math.prod(shape)
         total += n
-        if "emb" in path or "lm_head" in path:
+        if _embedding(path):
             embed += n
     return {"total": total, "embed": embed, "non_embed": total - embed}
 
 
-def model_flops(shapes: dict, tokens: int) -> float:
-    """Training FLOPs of ``tokens`` tokens: 6 * N_non_embedding * tokens."""
-    return 6.0 * param_counts(shapes)["non_embed"] * tokens
+def model_flops(shapes: dict, tokens: int, active: dict | None = None
+                ) -> float:
+    """Training FLOPs of ``tokens`` tokens: 6 * N_active_non_embedding *
+    tokens, where ``active`` maps a path to the share of that parameter
+    a token uses (1 for every path it does not name)."""
+    active = active or {}
+    n = sum(math.prod(shape) * active.get(path, 1)
+            for path, shape in shapes.items() if not _embedding(path))
+    return 6.0 * n * tokens

@@ -22,6 +22,8 @@ each with one per-tensor scale).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -79,18 +81,42 @@ def adamw(params, m, v, grads, t, h):
 
 
 def reference_steps(ref, config: dict, h: dict, quant: str = "f32"):
-    """Jitted ``(params, m, v, t, tokens) -> (params, m, v, loss,
-    clipped-gradient leaf norms)`` of the reference."""
+    """``(params, m, v, t, tokens) -> (params, m, v, loss, clipped-gradient
+    leaf norms)`` of the reference, ``tokens`` a host array.  The loss and
+    its gradient are taken one row at a time and averaged: every row has
+    the same positions, so that is the batch's mean, and a batch that the
+    chip cannot hold at once in float32 still fits."""
     q = QUANT[quant]
 
-    def step(params, m, v, t, tokens):
+    @jax.jit
+    def row_grad(params, row):
         with jax.default_matmul_precision("highest"):
-            loss, grads = jax.value_and_grad(ref.loss)(params, tokens, config,
-                                                       q)
-            params, m, v, g = adamw(params, m, v, grads, t, h)
-        return params, m, v, loss, leaf_norms(g)
+            return jax.value_and_grad(ref.loss)(params, row, config, q)
 
-    return jax.jit(step, donate_argnums=(0, 1, 2))
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+    def update(params, m, v, grads, t, rows):
+        with jax.default_matmul_precision("highest"):
+            grads = jax.tree.map(lambda x: x / rows, grads)
+            params, m, v, g = adamw(params, m, v, grads, t, h)
+        return params, m, v, leaf_norms(g)
+
+    def step(params, m, v, t, tokens):
+        n = tokens.shape[0]
+        loss, grads = 0.0, None
+        for r in range(n):
+            lr, gr = row_grad(params, jnp.asarray(tokens[r:r + 1]))
+            loss += float(lr)
+            grads = gr if grads is None else _add(grads, gr)
+            del gr
+        params, m, v, gn = update(params, m, v, grads, t, jnp.float32(n))
+        return params, m, v, loss / n, gn
+
+    return step
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _add(a, b):
+    return jax.tree.map(jnp.add, a, b)
 
 
 @jax.jit
@@ -128,9 +154,8 @@ def run_reference(ref, config: dict, h: dict, make_params, key, batches,
     v = jax.tree.map(zeros, params)
     losses, grad_norms = [], None
     for t, tokens in enumerate(batches, start=1):
-        params, m, v, loss, gn = step(params, m, v, jnp.float32(t),
-                                      jnp.asarray(tokens))
-        losses.append(float(loss))
+        params, m, v, loss, gn = step(params, m, v, jnp.float32(t), tokens)
+        losses.append(loss)
         if grad_norms is None:
             grad_norms = np.asarray(gn, np.float64)
     del m, v

@@ -60,17 +60,26 @@ def adamw_zeros(params):
             "step": jnp.zeros((), jnp.int32)}
 
 
-def state_maker(layout):
-    """Jitted ``seed key -> {"params", "opt"}``, every leaf on the device."""
+def state_maker(layout, shardings=None):
+    """Jitted ``seed key -> {"params", "opt"}``, every leaf on the device,
+    or placed under ``shardings`` (the same values: the draws do not
+    depend on where they land)."""
     def make(key):
         params = draw_params(key, layout)
         return {"params": params, "opt": adamw_zeros(params)}
-    return jax.jit(make)
+    return _jit(make, shardings)
 
 
-def params_maker(layout):
-    """Jitted ``seed key -> params`` (the same draws as ``state_maker``)."""
-    return jax.jit(lambda key: draw_params(key, layout))
+def params_maker(layout, shardings=None):
+    """Jitted ``seed key -> params`` (the same draws as ``state_maker``),
+    under ``shardings`` (a params tree) where given."""
+    return _jit(lambda key: draw_params(key, layout), shardings)
+
+
+def _jit(fn, shardings):
+    if shardings is None:
+        return jax.jit(fn)
+    return jax.jit(fn, out_shardings=shardings)
 
 
 def shapes(layout) -> dict:

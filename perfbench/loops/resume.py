@@ -43,6 +43,9 @@ def leaves_differing(a, b):
 
 def run(ctx: Context) -> Outcome:
     cell, tr = ctx.cell, ctx.cell.traffic
+    if tr.get("layout") is not None:
+        raise ValueError("the resume loop runs on one device; it takes no "
+                         "layout")
     ref = spec.reference(cell)
     mc = program.model_config(cell, ref)
     layout = ref.layout(cell.config)

@@ -18,15 +18,20 @@ Traffic keys: ``seq_len``, ``batch``, ``capture`` ("fused" | "none"),
 ``optimizer`` (AdamW hyper-parameters; ``lr`` is passed to the step, the
 rest are the program's own and the reference uses them), ``check_steps``,
 ``warmup_steps``, ``saves`` (``first_at`` and ``every``, in window steps;
-null for no checkpointing) and ``pipeline`` (every ``PipelineSpec``
-field; null for no checkpointing).
+null for no checkpointing), ``pipeline`` (every ``PipelineSpec`` field;
+null for no checkpointing) and, optionally, ``layout`` (the mesh the state
+lives on, ``harness.program``; the reference replays the same global
+batch on one device).
+
+The record holds ``step_counters``: the window's sum of each scalar the
+step returns besides its loss, fetched with the loss, for readers of
+counters a configuration's step adds.
 """
 from __future__ import annotations
 
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from harness import flops, program, refcheck, rows, shardfile, spec, stats
@@ -48,9 +53,11 @@ def run(ctx: Context) -> Outcome:
     key = weights.seed_key(ctx.seed)
     shape = (tr["batch"], tr["seq_len"], mc.vocab_size)
     pipe = program.pipeline_spec(tr) if tr.get("saves") else None
+    mesh = program.mesh(tr, cell.chips)
     client = program.client(pipe, str(ctx.scratch)) if pipe else None
     try:
-        w = _train(ctx, mc, layout, key, shape, pipe, client)
+        with program.use_mesh(mesh):
+            w = _train(ctx, mc, layout, key, shape, pipe, client, mesh)
     finally:
         if client is not None:
             client.shutdown()
@@ -66,6 +73,8 @@ def run(ctx: Context) -> Outcome:
     g = refcheck.gaps(w["prog"], got)
     checks = {k: (g[k], lim) for k, lim in cell.limits.items()}
     checks["window_losses_nonfinite"] = (w["nonfinite"], 0)
+    if mesh is not None:
+        checks["state_leaves_off_mesh"] = (w["off_mesh"], 0)
     if pipe is not None:
         checks["ckpt_regions_differing"] = (len(w["bad_regions"]), 0)
         for b in w["bad_regions"][:8]:
@@ -85,9 +94,12 @@ def run(ctx: Context) -> Outcome:
         q = [round(stats.percentile(times, p) * 1e3, 3)
              for p in (5, 25, 50, 75, 90, 95, 99, 100)]
         ctx.log(f"step ms at p5/25/50/75/90/95/99/max: {q}")
+    active = ref.active_shares(c) if hasattr(ref, "active_shares") else None
     record = {"window_s": w["window_s"], "steps": len(times),
               "tokens_per_s": e2e["tokens_per_s"],
-              "flops_per_token": flops.model_flops(weights.shapes(layout), 1),
+              "flops_per_token": flops.model_flops(weights.shapes(layout), 1,
+                                                   active),
+              "step_counters": w["counters"],
               "step_times": times, "saves": saves,
               "region_bytes": w["region_bytes"],
               "state_bytes": sum(w["region_bytes"])}
@@ -97,25 +109,32 @@ def run(ctx: Context) -> Outcome:
                    window_compiles=w["window_compiles"], trace=w["trace"])
 
 
-def _train(ctx, mc, layout, key, shape, pipe, client) -> dict:
-    """Set-up, the window and the read-back.  Returns host values only,
-    so the program's device state is freed when it returns."""
+def _train(ctx, mc, layout, key, shape, pipe, client, mesh) -> dict:
+    """Set-up, the window and the read-back, on ``mesh`` where there is
+    one.  Returns host values only, so the program's device state is freed
+    when it returns."""
     tr = ctx.cell.traffic
     capture = tr["capture"] == "fused"
     saves = tr.get("saves")
     b1 = tr["optimizer"]["b1"]
     gstep = 0
 
-    step_fn = program.train_step(mc, tr)
-    state = weights.state_maker(layout)(key)
+    sh = program.state_shardings(mc, mesh, weights.state_maker(layout))
+    feed = program.feeder(mesh)
+    step_fn = program.train_step(mc, tr, sh)
+    make_state = weights.state_maker(layout, sh)
+    state = make_state(key)
     program.check_state(state, mc)
+    off_mesh = program.off_mesh(state, mesh) if mesh is not None else 0
+    fetched = {}
 
     def one(state):
-        """One step as ``_train`` runs it: tick, feed, step, tick, loss."""
+        """One step as ``_train`` runs it: tick, feed, step, tick, loss
+        (and the step's other scalars, in the same transfer)."""
         nonlocal gstep
         if client is not None:
             client.tick("step_begin")
-        batch = {"tokens": jnp.asarray(rows.tokens(ctx.seed, gstep, *shape))}
+        batch = feed(rows.tokens(ctx.seed, gstep, *shape))
         if capture:
             state, snap, metrics = step_fn(state, batch)
         else:
@@ -123,12 +142,14 @@ def _train(ctx, mc, layout, key, shape, pipe, client) -> dict:
         if client is not None:
             client.tick("step_end")
         gstep += 1
-        return state, snap, float(metrics["loss"])
+        fetched.update(jax.device_get(metrics))
+        return state, snap, float(fetched["loss"])
 
     # -- the first steps, which the reference follows ----------------------
     state, snap, prog = refcheck.program_readings(
         one, state, tr["check_steps"], b1,
-        lambda: weights.params_maker(layout)(key))
+        lambda: weights.params_maker(
+            layout, sh["params"] if sh is not None else None)(key))
     for _ in range(tr["warmup_steps"]):
         snap = None
         state, snap, loss = one(state)
@@ -136,7 +157,7 @@ def _train(ctx, mc, layout, key, shape, pipe, client) -> dict:
         program.warm_save_path(snap if snap is not None else state)
 
     # -- the window --------------------------------------------------------
-    step_times, losses, saved = [], [], []
+    step_times, losses, saved, counters = [], [], [], {}
     if ctx.trace:
         tracing.start(str(ctx.trace_dir))
     compiles0 = ctx.compiles.total()
@@ -158,6 +179,9 @@ def _train(ctx, mc, layout, key, shape, pipe, client) -> dict:
                                             saved))
             step_times.append(time.perf_counter() - t0)
             losses.append(loss)
+            for k, v in fetched.items():
+                if k != "loss":
+                    counters[k] = counters.get(k, 0.0) + float(v)
             i += 1
         t_close = time.perf_counter()
         for sv in saved:   # the traced window runs on until they are durable
@@ -169,8 +193,7 @@ def _train(ctx, mc, layout, key, shape, pipe, client) -> dict:
         path = tracing.newest_xplane(str(ctx.trace_dir))
         summary = tracing.reduce(tracing.load_events(path)) if path else None
     mem = memory_peak_bytes()
-    region_bytes = [int(np.prod(x.shape)) * x.dtype.itemsize
-                    for x in jax.tree.leaves(state)]
+    region_bytes = _region_bytes(state)
     del state, snap
 
     # -- each save read back from each level, against the same state
@@ -188,13 +211,28 @@ def _train(ctx, mc, layout, key, shape, pipe, client) -> dict:
         save_rows.append({"protect_s": sv.get("t_done", np.nan) - sv["t"],
                           "app_blocking_s": res.get("app_blocking_s"),
                           "shard_bytes": res.get("shard_bytes")})
-    bad_regions = _read_back(ctx, client, pipe, saved, step_fn, layout, key,
+    bad_regions = _read_back(ctx, client, pipe, saved, step_fn,
+                             lambda: make_state(key), feed,
                              shape) if saved else []
     return {"prog": prog, "setup_s": setup_s, "window_s": t_close - t_open,
             "step_times": step_times, "nonfinite": nonfinite,
             "failed": failed, "saves": save_rows, "bad_regions": bad_regions,
             "region_bytes": region_bytes, "memory_peak_bytes": mem,
-            "window_compiles": window_compiles, "trace": summary}
+            "window_compiles": window_compiles, "trace": summary,
+            "counters": counters, "off_mesh": off_mesh}
+
+
+def _region_bytes(state) -> list:
+    """Bytes of each region a save of ``state`` writes: one per leaf, or
+    one per distinct shard of a leaf split over a mesh."""
+    out = []
+    for x in jax.tree.leaves(state):
+        parts = {}
+        for s in x.addressable_shards:
+            starts = tuple(i.start or 0 for i in s.index)
+            parts.setdefault(starts, s.data.nbytes)
+        out += parts.values()
+    return out
 
 
 def _save(client, state, snap, version, loss, saved) -> dict:
@@ -218,18 +256,17 @@ def _settled(sv) -> bool:
     return fut.exception(0) is None and not fut.module_errors
 
 
-def _read_back(ctx, client, pipe, saved, step_fn, layout, key,
+def _read_back(ctx, client, pipe, saved, step_fn, make_state, feed,
                shape) -> list:
     """Regions of each save that differ, at each level, from the state of
     its version.  The device held that state only while the save copied
     it, so it is made again: the same weights and rows through the same
     compiled step, which gives the same bits."""
     todo = {sv["version"]: sv for sv in saved}
-    state = weights.state_maker(layout)(key)
+    state = make_state()
     bad = []
     for s in range(max(todo)):
-        out = step_fn(state, {"tokens": jnp.asarray(
-            rows.tokens(ctx.seed, s, *shape))})
+        out = step_fn(state, feed(rows.tokens(ctx.seed, s, *shape)))
         state = out[0]
         del out
         sv = todo.get(s + 1)

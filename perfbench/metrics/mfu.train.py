@@ -1,6 +1,6 @@
-"""mfu.train: model FLOPs of the window's steps per second (6 x non-embedding
-parameters x tokens/s, ``harness.flops``) over the chip's bf16 peak.  Moves
-tokens_per_s."""
+"""mfu.train: model FLOPs of the window's steps per second (6 x active
+non-embedding parameters x tokens/s, ``harness.flops``) over the bf16 peak
+of the cell's chips.  Moves tokens_per_s."""
 from harness import readings
 
 
@@ -8,4 +8,5 @@ def read(run):
     p = readings.peak(run, "bf16_flops")
     if p is None or not run.get("tokens_per_s"):
         return None
-    return 100.0 * run["flops_per_token"] * run["tokens_per_s"] / p
+    return 100.0 * run["flops_per_token"] * run["tokens_per_s"] \
+        / (run.get("chips", 1) * p)

@@ -32,6 +32,16 @@ HIGHEST = jax.lax.Precision.HIGHEST
 VOCAB_PAD = 256
 ATTN_CHUNK = 1024
 
+#: the sizes the harness's CPU tests cut the configuration file to (every
+#: width cut, every structure kept), and the program configuration's
+#: fields that say the same
+TINY = {"file": {"hidden_size": 64, "num_attention_heads": 4,
+                 "num_key_value_heads": 4, "intermediate_size": 128,
+                 "vocab_size": 500, "num_hidden_layers": 2},
+        "program": {"d_model": 64, "num_heads": 4, "num_kv_heads": 4,
+                    "head_dim": 16, "d_ff": 128, "vocab_size": 500,
+                    "num_layers": 2, "remat": False}}
+
 
 def dims(c: dict) -> dict:
     if c.get("sliding_window") is not None:

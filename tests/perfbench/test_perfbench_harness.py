@@ -59,6 +59,8 @@ def test_benchmark_json_keeps_its_contract(bench):
         for w in m["workloads"]:   # each cell listed reports the moved one
             assert w in e2e[m["moves"]].get("workloads", cells)
         assert 1 <= len(m["layer"]) <= 200 and "\n" not in m["layer"]
+    four = sum(1 for w in bench["workloads"] if w["chips"] == 4)
+    assert four <= max(1, len(cells) // 2)
     for w in cells:   # setup_s, one more end-to-end and one per-layer metric
         assert sum(1 for m in bench["end_to_end"]
                    if w in m.get("workloads", cells)) >= 2
@@ -72,6 +74,7 @@ def test_every_file_of_a_cell_loads_by_name(workload, tmp_path):
     assert cell.config["name"] == cell.config_name
     ref = spec.reference(cell)
     assert callable(ref.loss) and callable(ref.layout)
+    assert set(ref.TINY) == {"file", "program"}   # its own CPU sizes
     assert callable(spec.loop(cell).run)
     assert cell.limits
     for m in cell.per_layer:
@@ -118,6 +121,64 @@ def test_model_counts():
     assert flops.param_counts(shapes) == {"total": 96, "embed": 80,
                                           "non_embed": 16}
     assert flops.model_flops(shapes, 3) == 6 * 16 * 3
+
+
+def test_model_flops_counts_the_share_a_token_uses():
+    shapes = {"['emb']": (10, 4), "['blocks'][0]['w']": (4, 4),
+              "['blocks'][0]['experts']": (8, 4, 4), "['lm_head']": (4, 10)}
+    dense = flops.model_flops(shapes, 3)
+    assert dense == 6.0 * (16 + 128) * 3
+    # every leaf wholly active reads as the dense count, bit for bit
+    assert flops.model_flops(shapes, 3, {}) == dense
+    assert flops.model_flops(shapes, 3, {p: 1 for p in shapes}) == dense
+    # a token uses 2 of the 8 experts
+    top2 = flops.model_flops(shapes, 3, {"['blocks'][0]['experts']": 0.25})
+    assert top2 == 6.0 * (16 + 32) * 3
+
+
+def test_mfu_divides_by_the_cells_chips():
+    read = spec.metric_reader(spec.load_cell("phi3-train-async-full"),
+                              "mfu.train")
+    run = {"on_chip": True, "device_kind": "TPU v5 lite",
+           "tokens_per_s": 30000.0, "flops_per_token": 6 * 113e6}
+    one = read(dict(run, chips=1))
+    assert one == read(run) == 100.0 * 6 * 113e6 * 3e4 / 197e12
+    assert read(dict(run, chips=4)) == pytest.approx(one / 4)
+
+
+def test_reference_by_rows_is_the_mean_of_the_batch():
+    import jax
+    import jax.numpy as jnp
+
+    from harness import refcheck, weights
+
+    cell = spec.load_cell("phi3-train-nockpt")
+    tiny.shrink(cell)
+    c, ref, h = cell.config, spec.reference(cell), cell.traffic["optimizer"]
+    make = weights.params_maker(ref.layout(c))
+    key = weights.seed_key(7)
+    tokens = np.arange(3 * 16).reshape(3, 16) * 7 % c["vocab_size"]
+    got = refcheck.run_reference(ref, c, h, make, key, [tokens])
+    with jax.default_matmul_precision("highest"):
+        params = make(key)
+        loss, grads = jax.value_and_grad(ref.loss)(params, jnp.asarray(
+            tokens), c)
+        zeros = jax.tree.map(jnp.zeros_like, params)
+        new, _, _, g = refcheck.adamw(params, zeros, zeros, grads, 1.0, h)
+    assert got["losses"][0] == pytest.approx(float(loss), rel=1e-6)
+    np.testing.assert_allclose(got["grad_norms"],
+                               np.asarray(refcheck.leaf_norms(g)), rtol=1e-5)
+    np.testing.assert_allclose(
+        got["change_norms"], np.asarray(refcheck.change_norms(new, params)),
+        rtol=1e-4)
+
+
+def test_layout_mesh_is_the_cells_chips():
+    from harness import program
+
+    assert program.mesh({}, 1) is None          # no layout: one device
+    with pytest.raises(ValueError):
+        program.mesh({"layout": {"mesh": {"data": 4, "model": 1}}}, 1)
 
 
 def _events():
@@ -208,6 +269,41 @@ def test_shard_reader_finds_a_changed_byte():
     assert shardfile.mismatches(bytes(bad), [a, b]) == ["b"]
     assert shardfile.mismatches(blob, [a]) == ["2 regions for 1 leaves"]
     assert shardfile.mismatches(b"junk" * 4, [a])[0].startswith("unreadable")
+
+
+def _sharded(a, b, drop=None):
+    """A shard holding ``a`` as four row blocks and ``b`` whole, as a save
+    of one process's four devices writes them."""
+    from repro.core import format as fmt
+
+    regions = [fmt.Region(f"['a']@{i},0", a[i:i + 2], global_shape=a.shape)
+               for i in range(0, 8, 2) if i != drop]
+    return fmt.serialize_shard(regions + [fmt.Region("['b']", b)], {})
+
+
+def test_shard_reader_compares_each_shard_with_its_slice():
+    a = np.arange(32, dtype=np.float32).reshape(8, 4)
+    b = np.arange(5, dtype=np.int32)
+    blob = _sharded(a, b)
+    assert shardfile.mismatches(blob, [a, b]) == []
+    # a changed byte in one shard's region names that shard
+    start = next(r["start"] for r in shardfile.regions(blob)
+                 if r["name"] == "['a']@4,0")
+    bad = bytearray(blob)
+    bad[start + 3] ^= 1
+    assert shardfile.mismatches(bytes(bad), [a, b]) == ["['a']@4,0"]
+    # the shard compared with another slice of its leaf differs
+    c = a.copy()
+    c[6:] += 1
+    assert shardfile.mismatches(blob, [c, b]) == ["['a']@6,0"]
+    # a missing shard, and a surplus leaf
+    assert shardfile.mismatches(_sharded(a, b, drop=2), [a, b]) == [
+        "['a']: shards cover 24 of 32 elements"]
+    assert shardfile.mismatches(blob, [a]) == ["2 regions for 1 leaves"]
+    # a shard whose header names another global shape
+    assert shardfile.mismatches(blob, [a[:6], b]) == [
+        f"['a']@{i},0" for i in range(0, 8, 2)] + [
+        "['a']: shards cover 0 of 24 elements"]
 
 
 def test_no_chip_no_result(tmp_path):
